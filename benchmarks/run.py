@@ -580,8 +580,8 @@ def check_chain(shards: int = 2) -> None:
     """--check leg for the workload/chain subsystem: the depth-3 seeded
     chain must run to completion on all three engines, replay bit-identical
     under the fixed seed, pass the xlb p99 gate, and drive every hop
-    through the mesh-sharded admission datapath (--shards 2 on a virtual
-    host mesh, in a subprocess)."""
+    through the mesh-sharded admission datapath (--shards 2, see
+    ``_sharded_leg``)."""
     from benchmarks import common
     depth, n_req = 3, 8
     rows = {}
@@ -606,16 +606,22 @@ def check_chain(shards: int = 2) -> None:
     print("# check: chain replay OK — bit-identical scenario row under "
           "seed 11", flush=True)
     _gate_chain(list(rows.values()))
-    code = ("import sys; from benchmarks.common import run_chain_scenario; "
-            "from benchmarks.run import _chain_workload; "
-            f"out = run_chain_scenario('xlb', depth={depth}, "
-            f"shards={shards}, workload=_chain_workload("
-            f"n_requests={n_req})); "
-            f"sys.exit(0 if out['row']['completed'] == {n_req} else 1)")
-    _run_on_host_mesh(["-c", code], shards,
-                      what="check: sharded chain smoke")
+    _sharded_leg(shards, _sharded_chain_leg, depth, n_req, shards,
+                 what="check: sharded chain smoke")
     print(f"# check: sharded chain smoke OK — xlb --shards {shards} "
           f"{n_req}/{n_req}", flush=True)
+
+
+def _sharded_chain_leg(depth: int, n_req: int, shards: int) -> None:
+    """The seeded chain on xlb with every hop on a ``shards``-way mesh;
+    every request must complete (a ``_sharded_leg``)."""
+    from benchmarks import common
+    out = common.run_chain_scenario(
+        "xlb", depth=depth, shards=shards,
+        workload=_chain_workload(n_requests=n_req))
+    if out["row"]["completed"] != n_req:
+        sys.exit(f"check: sharded chain smoke FAILED — completed "
+                 f"{out['row']['completed']}/{n_req}")
 
 
 def _gate_degraded(r: dict) -> None:
@@ -825,35 +831,62 @@ def check_chaos() -> None:
           f"channel counters under seed {out['row']['seed']}", flush=True)
 
 
-def _run_on_host_mesh(argv: list, shards: int, *, what: str,
+def _sharded_leg(shards: int, leg, *args, what: str, timeout: int = 1800):
+    """Call ``leg(*args)``, a module-level function that needs ``shards``
+    devices, and return its JSON-able result.  A process that already sees
+    them (a TPU host with enough chips) calls it in-process: a chip belongs
+    to one process, so a child started after this process touched JAX
+    would fail or hang.  Only the CPU rehearsal, whose one host device
+    cannot be split after start-up, calls the same function by name in a
+    child on a virtual host mesh; any other backend short of devices is
+    refused."""
+    import jax
+    n, kind = len(jax.devices()), jax.default_backend()
+    if n >= shards:
+        return leg(*args)
+    if kind != "cpu":
+        sys.exit(f"{what} FAILED — needs {shards} devices, this {kind} "
+                 f"host has {n} (a child process cannot share the chip)")
+    return _run_on_host_mesh(shards, leg, args, what=what, timeout=timeout)
+
+
+def _run_on_host_mesh(shards: int, leg, args: tuple, *, what: str,
                       timeout: int = 1800):
-    """Run a python subprocess on an M-device virtual host mesh (XLA_FLAGS
-    must precede jax init, and this process already booted a 1-device
-    jax).  Exits with the captured output on failure."""
+    """Call ``leg(*args)`` in a python subprocess on an M-device virtual
+    host mesh (XLA_FLAGS must precede jax init, and this process already
+    booted a 1-device jax) — the CPU rehearsal path of ``_sharded_leg``.
+    The child prints the result as JSON on its last stdout line.  Exits
+    with the captured output on failure."""
     import os
     import subprocess
+    # under ``python -m benchmarks.run`` this module is ``__main__``
+    spec = sys.modules[leg.__module__].__spec__
+    module = spec.name if spec else leg.__module__
+    code = (f"import json; from {module} import {leg.__name__} as leg; "
+            f"print(json.dumps(leg(*{tuple(args)!r})), flush=True)")
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={shards}"
     env["PYTHONPATH"] = "src" + os.pathsep + env.get("PYTHONPATH", "")
-    out = subprocess.run([sys.executable] + argv, env=env,
+    out = subprocess.run([sys.executable, "-c", code], env=env,
                          capture_output=True, text=True, timeout=timeout)
     if out.returncode != 0:
         sys.exit(f"{what} FAILED —\n{out.stdout}\n{out.stderr[-2000:]}")
-    return out
+    return json.loads(out.stdout.strip().splitlines()[-1])
 
 
 def bench_shard():
     """Sharded-admission microbenchmark (ROADMAP scale-out): the mesh-
     sharded datapath (``ops.admit_commit_sharded`` — per-shard fused kernel
     + psum reconciliation + commit relay) vs the single-shard fused kernel
-    on the same batch, on an M-way host mesh.  Runs the measurement in a
-    subprocess (``benchmarks/shard_bench.py``).  Rows append to
+    on the same batch, on an M-way mesh (``benchmarks/shard_bench.py``,
+    in-process or on a virtual host mesh — ``_sharded_leg``).  Rows append to
     BENCH_TREND.jsonl; the CPU-interpreter ratio is advisory (M "hosts"
     timeshare one machine) — the real read is the TPU leg."""
+    from benchmarks import shard_bench
     shards = 2
-    out = _run_on_host_mesh(["-m", "benchmarks.shard_bench", str(shards)],
-                            shards, what="bench_shard worker")
-    record = json.loads(out.stdout.strip().splitlines()[-1])
+    record = _sharded_leg(shards, shard_bench.measure, shards,
+                          what="bench_shard worker")
     for b, s1, s2, r in zip(record["batch"], record["single_us"],
                             record["sharded_us"], record["ratio"]):
         emit("shard", "single", f"us@{b}", s1)
@@ -942,7 +975,9 @@ def check_sanitize() -> None:
     measured path."""
     import os
     import subprocess
-    env = {**os.environ, "XLB_SANITIZE": "1"}
+    # the suite is the CPU suite (kernels in interpret mode): its process is
+    # pinned to the CPU, so it never contends for a chip this one holds
+    env = {**os.environ, "XLB_SANITIZE": "1", "JAX_PLATFORMS": "cpu"}
     env["PYTHONPATH"] = os.pathsep.join(
         p for p in ("src", env.get("PYTHONPATH", "")) if p)
     t0 = time.perf_counter()
@@ -956,6 +991,12 @@ def check_sanitize() -> None:
           f"({dt:.0f}s)", flush=True)
 
 
+# the launcher defaults to the paper's 50-instance fleet; the --check
+# smokes pass their own CPU-sized one
+_TINY_FLEET = ("--instances", "2", "--slots", "2", "--admit-batch", "8",
+               "--max-len", "6")
+
+
 def smoke_engines() -> None:
     """Protocol-drift gate: boot ``launch/serve.py`` in-process for every
     engine kind at a tiny config and require full completion.  Catches
@@ -965,9 +1006,8 @@ def smoke_engines() -> None:
     from repro.launch import serve
     n_req = 4
     for kind in ENGINE_KINDS:
-        done = serve.main(["--engine", kind, "--instances", "2",
-                           "--slots", "2", "--requests", str(n_req),
-                           "--max-len", "6"])
+        done = serve.main(["--engine", kind, *_TINY_FLEET,
+                           "--requests", str(n_req)])
         if done != n_req:
             sys.exit(f"check: engine smoke FAILED — {kind} completed "
                      f"{done}/{n_req} requests")
@@ -975,17 +1015,24 @@ def smoke_engines() -> None:
               flush=True)
 
 
+def _serve_leg(argv: list, n_req: int) -> None:
+    """``launch/serve.py`` with ``argv``; every request must complete (a
+    ``_sharded_leg`` when ``argv`` asks for ``--shards``)."""
+    from repro.launch import serve
+    done = serve.main(argv)
+    if done != n_req:
+        sys.exit(f"serve {' '.join(argv)} FAILED — completed "
+                 f"{done}/{n_req} requests")
+
+
 def smoke_shards(shards: int = 2) -> None:
     """--check gate for the scale-out layer: boot ``launch/serve.py
-    --shards 2`` on a virtual host mesh and require every request to
-    complete through the sharded admission datapath."""
+    --shards 2`` and require every request to complete through the
+    sharded admission datapath."""
     n_req = 4
-    code = ("import sys; from repro.launch.serve import main; "
-            f"sys.exit(0 if main(['--shards', '{shards}', "
-            f"'--instances', '2', '--slots', '2', '--requests', "
-            f"'{n_req}', '--max-len', '6']) == {n_req} else 1)")
-    _run_on_host_mesh(["-c", code], shards, what="check: sharded serve "
-                      "smoke", timeout=1200)
+    _sharded_leg(shards, _serve_leg, ["--shards", str(shards), *_TINY_FLEET,
+                                      "--requests", str(n_req)], n_req,
+                 what="check: sharded serve smoke", timeout=1200)
     print(f"# check: sharded serve smoke OK — --shards {shards} "
           f"{n_req}/{n_req}", flush=True)
 
@@ -995,22 +1042,14 @@ def smoke_policies(shards: int = 2) -> None:
     the hash-keyed policies — maglev in-process on one host, affinity on a
     2-way sharded mesh (exercising the affinity-cache reconciliation
     collective end-to-end)."""
-    from repro.launch import serve
     n_req = 4
-    done = serve.main(["--engine", "xlb", "--policy", "maglev",
-                       "--instances", "2", "--slots", "2",
-                       "--requests", str(n_req), "--max-len", "6"])
-    if done != n_req:
-        sys.exit(f"check: policy smoke FAILED — maglev completed "
-                 f"{done}/{n_req} requests")
-    print(f"# check: policy smoke OK — maglev {done}/{n_req}", flush=True)
-    code = ("import sys; from repro.launch.serve import main; "
-            f"sys.exit(0 if main(['--policy', 'affinity', '--shards', "
-            f"'{shards}', '--instances', '2', '--slots', '2', "
-            f"'--requests', '{n_req}', '--max-len', '6']) == {n_req} "
-            "else 1)")
-    _run_on_host_mesh(["-c", code], shards, what="check: affinity sharded "
-                      "serve smoke", timeout=1200)
+    _serve_leg(["--engine", "xlb", "--policy", "maglev", *_TINY_FLEET,
+                "--requests", str(n_req)], n_req)
+    print(f"# check: policy smoke OK — maglev {n_req}/{n_req}", flush=True)
+    _sharded_leg(shards, _serve_leg,
+                 ["--policy", "affinity", "--shards", str(shards),
+                  *_TINY_FLEET, "--requests", str(n_req)], n_req,
+                 what="check: affinity sharded serve smoke", timeout=1200)
     print(f"# check: policy smoke OK — affinity --shards {shards} "
           f"{n_req}/{n_req}", flush=True)
 
@@ -1028,6 +1067,8 @@ BENCHES = {
 
 def main() -> None:
     global _POLICY
+    from repro.launch.cache import setup_compile_cache
+    setup_compile_cache()
     args = sys.argv[1:]
     if "--policy" in args:
         i = args.index("--policy")

@@ -1,30 +1,21 @@
-"""Worker for ``benchmarks/run.py::bench_shard`` — runs in its OWN process.
-
-The M-way host mesh needs ``XLA_FLAGS=--xla_force_host_platform_device_count``
-set before jax initializes, which the parent benchmark process (already
-holding a 1-device jax) cannot do; the parent spawns this module and parses
-the JSON record it prints on the last stdout line.
+"""Sharded-admission measurement for ``benchmarks/run.py::bench_shard``.
 
 Measures the mesh-sharded admission datapath (``ops.admit_commit_sharded``:
 per-shard fused kernel + psum reconciliation + commit relay, DESIGN.md §7)
-against the single-shard fused kernel on the same batch.  On the CPU
-interpreter the collectives pay host-loop overhead and the M "hosts"
-timeshare one machine, so the ratio here is an advisory trend row — the
-real read is the TPU leg, where the shards are distinct chips and the
-reconciliation is one ICI pass.
+against the single-shard fused kernel on the same batch.  ``measure`` runs
+on whatever devices its process sees: ``run._sharded_leg`` calls it
+in-process, or by name in a child on an M-device virtual host mesh for the
+CPU rehearsal.  On the CPU interpreter the collectives pay host-loop
+overhead and the M "hosts" timeshare one machine, so the ratio there is an
+advisory trend row — the real read is the TPU leg, where the shards are
+distinct chips and the reconciliation is one ICI pass.
 """
 
 from __future__ import annotations
 
-import json
-import os
-import sys
 
-
-def main() -> None:
-    shards = int(sys.argv[1]) if len(sys.argv) > 1 else 2
-    os.environ.setdefault(
-        "XLA_FLAGS", f"--xla_force_host_platform_device_count={shards}")
+def measure(shards: int) -> dict:
+    """Single-shard vs ``shards``-way sharded admission timings."""
     import jax
     import jax.numpy as jnp
 
@@ -66,8 +57,4 @@ def main() -> None:
         record["single_us"].append(round(t1, 2))
         record["sharded_us"].append(round(t2, 2))
         record["ratio"].append(round(t1 / t2, 3))
-    print(json.dumps(record), flush=True)
-
-
-if __name__ == "__main__":
-    main()
+    return record

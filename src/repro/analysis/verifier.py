@@ -596,7 +596,7 @@ class _Analyzer:
             self.set(env, eqn.outvars[0], _hull(rd(0), rd(1)))
             return
 
-        if name == "pjit":
+        if name in ("jit", "pjit"):
             iv = self._pjit_intrinsic(env, eqn)
             if iv is not None:
                 for v in eqn.outvars:

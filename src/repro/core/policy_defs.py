@@ -202,6 +202,14 @@ def maglev_row_inputs(cfg: dict, c: int) -> tuple:
 #   kth(k)             window offset of the k-th eligible endpoint
 #   cyc(k)             kth(k) with the segment fold's no-drain shortcut
 #   seg_rank(ids, mask, n)  the fold-seam rank helper
+#   window(t)          (BR, WE) window t[eidx] of an (E,) table
+#   take(t, ids)       t[ids] of a 1-D table at in-range (BR,) ids
+#   take2(t, r, c)     t[r, c] of a 2-D table at in-range (BR,) ids
+#   first_true(m)      argmax of a (BR, W) bool mask along lanes
+#   prefix_count(m)    inclusive running count of a (BR, W) mask along
+#                      lanes
+# Kernel hooks read tables only through these fold helpers: the onehot
+# fold's forms are the ones Mosaic compiles (kernels/route_match.py).
 #
 # oracle ctx (ref.admit_ref; numpy, mutated in place by the loop):
 #   loads, cur         (E,)/(CL,) live counters
@@ -297,14 +305,13 @@ def _lr_kernel(ctx):
     onehot fold finds it by a static-depth binary search (Mosaic-friendly,
     no sort)."""
     eok, eidx, rank_c = ctx.eok, ctx.eidx, ctx.rank_c
-    load = jnp.where(eok, ctx.loads[eidx], BIG)            # (BR, WE)
+    load = jnp.where(eok, ctx.window(ctx.loads), BIG)      # (BR, WE)
 
     def pick(v, n_prev):
         m = rank_c - n_prev                # rank among value-v ties
         elig = load <= v[:, None]
-        ec = jnp.cumsum(elig.astype(jnp.int32), axis=1)
-        return jnp.argmax(elig & (ec == (m + 1)[:, None]),
-                          axis=1).astype(jnp.int32)
+        ec = ctx.prefix_count(elig)
+        return ctx.first_true(elig & (ec == (m + 1)[:, None]))
 
     if ctx.fold == "segment":
         # per-CLUSTER water-fill tables: every request of a cluster shares
@@ -368,7 +375,7 @@ def _lr_host(h, c, elig, feats):
 # ---- weighted ------------------------------------------------------------- #
 
 def _wt_kernel(ctx):
-    w = jnp.where(ctx.eok, ctx.ew[ctx.eidx], 0.0)
+    w = jnp.where(ctx.eok, ctx.window(ctx.ew), 0.0)
     return jnp.argmax(jnp.where(ctx.eok, jnp.log(w + 1e-9) + ctx.gum,
                                 -jnp.inf), axis=1).astype(jnp.int32)
 
@@ -405,9 +412,9 @@ def _wt_host(h, c, elig, feats):
 
 def _maglev_kernel(ctx):
     T = ctx.mg_tab.shape[1]
-    t = ctx.mg_tab[ctx.cl, ctx.fkey % T]                   # window offsets
+    t = ctx.take2(ctx.mg_tab, ctx.cl, ctx.fkey % T)        # window offsets
     te = jnp.clip(ctx.estart + t, 0, ctx.ed.shape[0] - 1)
-    t_ok = (t >= 0) & (t < ctx.count) & (ctx.ed[te] == 0)
+    t_ok = (t >= 0) & (t < ctx.count) & (ctx.take(ctx.ed, te) == 0)
     return jnp.where(t_ok, t, ctx.cyc(ctx.fkey % ctx.cnt1)
                      ).astype(jnp.int32)
 
@@ -445,11 +452,11 @@ def _maglev_staged(s):
 def _aff_hit(ctx):
     A = ctx.aff_key.shape[0]
     s = ctx.fkey % A
-    ak = ctx.aff_key[s]
-    ae = ctx.aff_ep[s]
+    ak = ctx.take(ctx.aff_key, s)
+    ae = ctx.take(ctx.aff_ep, s)
     aec = jnp.clip(ae, 0, ctx.ed.shape[0] - 1)
     hit = ((ak == ctx.fkey) & (ae >= ctx.estart)
-           & (ae < ctx.estart + ctx.count) & (ctx.ed[aec] == 0))
+           & (ae < ctx.estart + ctx.count) & (ctx.take(ctx.ed, aec) == 0))
     return s, ak, ae, hit
 
 
@@ -477,8 +484,8 @@ def affinity_kernel_update(ctx, ep):
         nk = ctx.aff_key.at[tgt].set(ctx.fkey, mode="drop")
         ne = ctx.aff_ep.at[tgt].set(ep, mode="drop")
         return nk, ne
-    oh = (win[:, None] & (s[:, None] == jax.lax.broadcasted_iota(
-        jnp.int32, (win.shape[0], A), 1))).astype(jnp.int32)
+    oh = (jnp.where(win, s, A)[:, None] == jax.lax.broadcasted_iota(
+        jnp.int32, (win.shape[0], A), 1)).astype(jnp.int32)
     wrote = jnp.sum(oh, axis=0) > 0
     nk = jnp.where(wrote, jnp.sum(oh * ctx.fkey[:, None], axis=0),
                    ctx.aff_key)

@@ -171,8 +171,7 @@ def sharded_apply(x, idx, weights, n_dest: int, capacity: int, axis: str,
     (psum'd over ``axis``), matching the single-shard dispatch on the
     concatenated rows.
     """
-    from repro.compat import axis_size
-    M = axis_size(axis)
+    M = jax.lax.axis_size(axis)
     E_loc = n_dest // M
     # local dispatch into per-destination pools with per-source capacity
     buf, meta = relay_dispatch(x, idx, n_dest, capacity)       # (E, C, D)

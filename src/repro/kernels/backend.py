@@ -2,13 +2,18 @@
 
 Off-TPU (CPU CI, local runs) the kernels execute through the Pallas
 interpreter — bit-accurate against the BlockSpec pipeline; on a real TPU
-backend they lower to Mosaic.  Callers pass ``interpret=None`` to get the
-auto-selected mode, or force a bool explicitly (tests, debugging).
+backend they compile through Mosaic.  Callers pass ``interpret=None`` to
+get the auto-selected mode, or force a bool explicitly (tests, debugging).
 
 The same split selects the *aggregation strategy* of the datapath kernels
-(DESIGN.md §5): the dense one-hot folds are the Mosaic-lowerable form
-(iota/compare/cumsum — on TPU the reductions feed the MXU), while the
-scatter/sort segment folds are the form XLA:CPU executes in linear time.
+(DESIGN.md §5).  ``fold="onehot"`` is the form Mosaic compiles: table reads
+are one-hot compare/select reductions or lane barrel shifts, running counts
+are 0/1 bf16 triangular matmuls with f32 accumulation, and 2-D row reads
+and pool writes are one-hot matmuls over 8-bit planes — no gather,
+scatter, sort, cumsum or 2-D → 1-D reshape inside a kernel.
+``fold="segment"`` keeps gathers, scatter-adds and a sort, which only the
+interpreter runs, in linear time.  ``tests/test_tpu_compile.py`` compiles
+the onehot datapath kernels for a described v5e at serving shapes.
 ``resolve_fold`` picks per backend; the block-size autotuner
 (``kernels/tune.py``) can override both the fold and the tile shapes.
 """
@@ -36,8 +41,8 @@ def resolve_interpret(interpret: bool | None) -> bool:
 
 
 def default_fold() -> str:
-    """Mosaic needs the one-hot form; everything else runs the interpreter,
-    where the scatter/sort segment folds are linear-time."""
+    """Mosaic compiles only the one-hot form; everything else runs the
+    interpreter, where the scatter/sort segment folds are linear-time."""
     return "onehot" if backend_kind() == "tpu" else "segment"
 
 
@@ -48,3 +53,11 @@ def resolve_fold(fold: str | None) -> str:
     if fold not in FOLDS:
         raise ValueError(f"unknown fold strategy {fold!r}; one of {FOLDS}")
     return fold
+
+
+def tile_ok(block: int, full: int, align: int, *, interpret: bool) -> bool:
+    """Whether ``block`` rows tile a ``full``-row axis of a compiled kernel:
+    the whole axis, or a multiple of the hardware tile ``align`` (8 rows of
+    a 2-D block, 128 lanes of a 1-D one).  The interpreter takes any
+    block."""
+    return interpret or block >= full or block % align == 0

@@ -14,14 +14,16 @@ without a host round-trip).
   * slot free           — finished slots clear req_id/endpoint, zero their
                           length, and drop out of the active set.
 
-Grid: (I / BI,) sequential over instance-lane tiles.  The endpoint-load
+Grid: (ceil(I / BI),) sequential over instance-lane tiles; the wrapper
+pads the instance axis with empty lanes to whole tiles.  The endpoint-load
 decrements and per-service rx bytes accumulate in VMEM scratch across the
 grid and are folded into the (E,) / (S,) outputs on the last step — the same
 running-counter carry as the admit kernel (``kernels/route_match.py``).
 The per-tile aggregation goes through the shared segment-fold seam
 (``route_match._seg_sum``, DESIGN.md §5): ``fold="segment"`` scatter-adds
 into the scratch counters in O(tile) — the CPU-interpreter default —
-while ``fold="onehot"`` keeps the dense Mosaic-lowerable dispatch matrix.
+while ``fold="onehot"`` folds one pool column at a time through a dense
+compare/select reduction, the form Mosaic compiles for the TPU.
 
 The kernel also closes the health-observation loop (DESIGN.md §8): every
 step it folds the per-endpoint completion count (the same segment fold as
@@ -41,7 +43,6 @@ property-tested in tests/test_kernels.py under both folds).
 from __future__ import annotations
 
 import functools
-import math
 from typing import NamedTuple
 
 import jax
@@ -49,7 +50,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.backend import resolve_fold, resolve_interpret
+from repro.kernels.backend import resolve_fold, resolve_interpret, tile_ok
 from repro.kernels.route_match import _seg_sum, _table_spec
 
 RX_BYTES_PER_TOKEN = 2     # response payload attributed per decoded token
@@ -113,8 +114,6 @@ def _complete_kernel(preq_ref, pep_ref, psvc_ref, plen_ref, ptok_ref,
 
     E = load0_ref.shape[0]
     S = rx0_ref.shape[0]
-    BI, C = preq_ref.shape
-    N = BI * C
 
     act = pact_ref[...] > 0
     nxt = nxt_ref[...]
@@ -131,16 +130,15 @@ def _complete_kernel(preq_ref, pep_ref, psvc_ref, plen_ref, ptok_ref,
     done_ref[...] = done.astype(jnp.int32)
 
     # ---- load release (tiled segment fold over endpoints) -------------- #
-    epf = pep_ref[...].reshape(N)
-    rel = (done & (pep_ref[...] >= 0) & (pep_ref[...] < E)).reshape(N)
-    one = jnp.ones((N,), jnp.int32)
-    dec_s[...] = _seg_sum(dec_s[...], jnp.where(rel, jnp.clip(epf, 0, E - 1),
+    pep = pep_ref[...]
+    rel = done & (pep >= 0) & (pep < E)
+    one = jnp.ones(pep.shape, jnp.int32)
+    dec_s[...] = _seg_sum(dec_s[...], jnp.where(rel, jnp.clip(pep, 0, E - 1),
                                                 E), one, fold=fold)
 
     # ---- rx traffic metrics (per active slot, svc >= S drops) ---------- #
-    svcf = jnp.maximum(psvc_ref[...], 0).reshape(N)
-    actf = act.reshape(N)
-    rx_s[...] = _seg_sum(rx_s[...], jnp.where(actf, jnp.minimum(svcf, S), S),
+    svc = jnp.maximum(psvc_ref[...], 0)
+    rx_s[...] = _seg_sum(rx_s[...], jnp.where(act, jnp.minimum(svc, S), S),
                          RX_BYTES_PER_TOKEN * one, fold=fold)
 
     @pl.when(i == pl.num_programs(0) - 1)
@@ -154,6 +152,21 @@ def _complete_kernel(preq_ref, pep_ref, psvc_ref, plen_ref, ptok_ref,
                                  alpha_tput=alpha_tput)
         ewlout_ref[...] = ewl
         ewtout_ref[...] = ewt
+
+
+def check_block_i(I: int, block_i: int, *, interpret: bool) -> int:
+    """The completion tile rows for an ``I``-lane pool: ``block_i`` capped
+    at ``I``.  A compiled kernel needs whole (8, 128) tiles, so a tile
+    shorter than the pool must be a multiple of 8 rows; anything else is
+    refused rather than silently reshaped."""
+    block_i = min(block_i, I)
+    if block_i < 1:
+        raise ValueError(f"block_i must be >= 1, got {block_i}")
+    if not tile_ok(block_i, I, 8, interpret=interpret):
+        raise ValueError(f"block_i={block_i} does not tile a compiled "
+                         f"kernel: use a multiple of 8 or the whole pool "
+                         f"({I} lanes)")
+    return block_i
 
 
 def complete(pool_req_id, pool_endpoint, pool_svc, pool_length, pool_token,
@@ -178,12 +191,18 @@ def complete(pool_req_id, pool_endpoint, pool_svc, pool_length, pool_token,
         ep_inflight_ewma = jnp.zeros((E,), jnp.float32)
     if ep_tput_ewma is None:
         ep_tput_ewma = jnp.zeros((E,), jnp.float32)
-    block_i = max(1, math.gcd(I, block_i))     # tiles must cover I exactly
-    grid = (I // block_i,)
+    interpret = resolve_interpret(interpret)
+    block_i = check_block_i(I, block_i, interpret=interpret)
+    # pad the instance axis to whole tiles with empty lanes (inactive: no
+    # completion, release or rx), sliced off the outputs below
+    Ip = -(-I // block_i) * block_i
+    lanes = [pool_req_id, pool_endpoint, pool_svc, pool_length, pool_token,
+             pool_active, nxt]
+    fill = [-1, -1, 0, 0, 0, 0, 0]
+    lanes = [jnp.pad(a.astype(jnp.int32), ((0, Ip - I), (0, 0)),
+                     constant_values=f) for a, f in zip(lanes, fill)]
+    grid = (Ip // block_i,)
     lane = pl.BlockSpec((block_i, C), lambda i: (i, 0))
-    pool = [pool_req_id.astype(jnp.int32), pool_endpoint.astype(jnp.int32),
-            pool_svc.astype(jnp.int32), pool_length.astype(jnp.int32),
-            pool_token.astype(jnp.int32), pool_active.astype(jnp.int32)]
     o = pl.pallas_call(
         functools.partial(_complete_kernel, eos=eos, max_len=max_len,
                           fold=resolve_fold(fold),
@@ -195,7 +214,7 @@ def complete(pool_req_id, pool_endpoint, pool_svc, pool_length, pool_token,
         out_specs=[lane] * 7 + [_table_spec((E,)), _table_spec((S,)),
                                 _table_spec((E,)), _table_spec((E,)),
                                 _table_spec((E,))],
-        out_shape=[jax.ShapeDtypeStruct((I, C), jnp.int32)] * 7
+        out_shape=[jax.ShapeDtypeStruct((Ip, C), jnp.int32)] * 7
                   + [jax.ShapeDtypeStruct((E,), jnp.int32),
                      jax.ShapeDtypeStruct((S,), jnp.int32),
                      jax.ShapeDtypeStruct((E,), jnp.int32),
@@ -203,7 +222,9 @@ def complete(pool_req_id, pool_endpoint, pool_svc, pool_length, pool_token,
                      jax.ShapeDtypeStruct((E,), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((E,), jnp.int32),
                         pltpu.VMEM((S,), jnp.int32)],
-        interpret=resolve_interpret(interpret),
-    )(*pool, nxt.astype(jnp.int32), ep_load, rx_bytes,
-      ep_inflight_ewma.astype(jnp.float32), ep_tput_ewma.astype(jnp.float32))
+        interpret=interpret,
+        name="xlb_complete",
+    )(*lanes, ep_load, rx_bytes, ep_inflight_ewma.astype(jnp.float32),
+      ep_tput_ewma.astype(jnp.float32))
+    o = [x[:I] for x in o[:7]] + list(o[7:])
     return CompleteResult(*o)

@@ -52,7 +52,6 @@ from functools import lru_cache
 import jax
 import jax.numpy as jnp
 
-from repro.compat import axis_size, shard_map
 from repro.core import policy_defs, relay, router
 from repro.core.routing_table import MAX_EPS_PER_CLUSTER, RoutingState
 from repro.kernels import completion as _cp
@@ -139,7 +138,7 @@ def _shard_body(rid, sv, feats, mb, tok, rnd, gum, state: RoutingState,
                 preq, pep, psvc, plen, ptok, pact, *, axis: str,
                 block_r: int, fold: str, interpret: bool):
     """shard_map body: local fused admit + the collective reconciliation."""
-    M = axis_size(axis)
+    M = jax.lax.axis_size(axis)
     m = jax.lax.axis_index(axis)
     E = state.ep_load.shape[0]
     CL = state.cluster_ep_count.shape[0]
@@ -281,7 +280,7 @@ def _build(mesh, axis: str, R_loc: int, block_r: int, fold: str,
                    interpret=interpret)
     sh = P(axis)
     rep = P()
-    f = shard_map(
+    f = jax.shard_map(
         body, mesh=mesh,
         in_specs=(sh, sh, sh, sh, sh, sh, sh, rep) + (sh,) * 6,
         out_specs=(sh,) * 5 + (rep,) * 8 + (sh,) * 6,
@@ -386,7 +385,7 @@ def _build_complete(mesh, axis: str, eos: int, max_len: int, block_i: int,
                    alpha_inflight=alpha_inflight, alpha_tput=alpha_tput)
     sh = P(axis)
     rep = P()
-    f = shard_map(
+    f = jax.shard_map(
         body, mesh=mesh,
         in_specs=(sh,) * 7 + (rep,) * 4,
         out_specs=(sh,) * 7 + (rep,) * 5,

@@ -117,7 +117,13 @@ def _sweep(key: tuple, candidates, make_fn) -> int:
 
 
 def _admit_candidates(R: int) -> list[int]:
-    return sorted({min(b, R) for b in BLOCK_R_CANDIDATES})
+    """Tile rows that tile an ``R``-row batch on the tuned backend
+    (``route_match.admit_tile_ok``): an illegal tile is never offered, and
+    a legal one that fails to compile raises out of the sweep."""
+    from repro.kernels.route_match import admit_tile_ok
+    interp = backend.default_interpret()
+    return sorted({min(b, R) for b in BLOCK_R_CANDIDATES
+                   if admit_tile_ok(b, R, interpret=interp)})
 
 
 def _synthetic_admit(R: int, I: int, C: int, fold: str, commit: bool):
@@ -198,7 +204,11 @@ def plan_admit(R: int, pool_shape: tuple, *, block_r: int | None = None,
 
 
 def _complete_candidates(I: int) -> list[int]:
-    return sorted({math.gcd(I, max(1, b)) for b in BLOCK_I_CANDIDATES + (I,)})
+    """Tile lanes for an ``I``-lane pool on the tuned backend (multiples of
+    8 rows or the whole pool when compiled; the kernel pads the rest)."""
+    interp = backend.default_interpret()
+    return sorted({min(b, I) for b in BLOCK_I_CANDIDATES + (I,)
+                   if backend.tile_ok(b, I, 8, interpret=interp)})
 
 
 def plan_complete(pool_shape: tuple, *, block_i: int | None = None,
@@ -219,7 +229,7 @@ def plan_complete(pool_shape: tuple, *, block_i: int | None = None,
         return _cache[key], fold
     cands = _complete_candidates(I)
     if not autotune_enabled() or len(cands) == 1:
-        return math.gcd(I, DEFAULT_BLOCK_I), fold
+        return min(DEFAULT_BLOCK_I, I), fold
 
     def make_fn(b):     # called under _sweep's compile-time-eval guard
         pool = [jnp.full((I, C), -1, jnp.int32),

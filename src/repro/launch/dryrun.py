@@ -321,9 +321,6 @@ def main():
     ap.add_argument("--variant", default="")
     args = ap.parse_args()
 
-    jax.config.update("jax_compilation_cache_dir", "/tmp/jax-cache")
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 5)
-
     cells = []
     archs = [args.arch] if args.arch else ASSIGNED_ARCHS
     shapes = [args.shape] if args.shape else list(SHAPES)

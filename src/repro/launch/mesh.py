@@ -7,10 +7,13 @@ touches jax device state; dryrun.py sets XLA_FLAGS before calling.
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
-
-from repro.compat import make_mesh
+from jax.sharding import AxisType, Mesh
 from repro.sharding.specs import MeshSpec
+
+
+def _auto_mesh(shape, axes) -> Mesh:
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -20,7 +23,7 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_mesh_spec(*, multi_pod: bool = False) -> MeshSpec:
@@ -31,7 +34,7 @@ def make_host_mesh(data: int = 1, model: int = 1) -> Mesh:
     """Small mesh over however many (host) devices exist — tests/examples."""
     n = len(jax.devices())
     assert data * model <= n, (data, model, n)
-    return make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))
 
 
 def make_shard_mesh(shards: int, axis: str = "shard") -> Mesh:
@@ -48,4 +51,4 @@ def make_shard_mesh(shards: int, axis: str = "shard") -> Mesh:
             f"found {n}; off-TPU set "
             "XLA_FLAGS=--xla_force_host_platform_device_count="
             f"{shards} before jax initializes")
-    return make_mesh((shards,), (axis,))
+    return _auto_mesh((shards,), (axis,))

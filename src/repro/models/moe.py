@@ -21,8 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
-
 from repro.configs.base import ModelConfig
 from repro.core import relay
 from repro.models.layers import Params, dense_init, ffn, init_ffn, split_keys
@@ -161,7 +159,7 @@ def moe_ffn(cfg: ModelConfig, p: Params, x: jax.Array, *,
                       "w_out": P("model", None, dp_axes)}
         else:
             wspecs = {n: P("model", None, None) for n in wdict}
-        out_flat, overflow, load = shard_map(
+        out_flat, overflow, load = jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(tok_axes, None), P(tok_axes), P(tok_axes), wspecs),
             out_specs=(P(tok_axes, None), P(), P()),

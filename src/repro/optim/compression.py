@@ -56,7 +56,6 @@ def cross_pod_allreduce(grads, ef: EFState, axis: str = "pod"):
     # int8 psum: sum of quantised values stays exact in int32
     q32 = jax.tree.map(lambda x: jax.lax.psum(x.astype(jnp.int32), axis), q)
     s = jax.tree.map(lambda x: jax.lax.pmax(x, axis), s)
-    from repro.compat import axis_size
-    n = axis_size(axis)
+    n = jax.lax.axis_size(axis)
     deq = jax.tree.map(lambda qi, si: qi.astype(jnp.float32) * si / n, q32, s)
     return deq, ef

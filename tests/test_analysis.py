@@ -94,7 +94,7 @@ def test_mutation_unclamped_ref_index_is_rejected():
 
 
 def test_mutation_x64_promotion_is_rejected():
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         x = jnp.zeros((4,), jnp.float64)
         out = verify_fn(lambda a: a * 2.0, (x,), name="mut")
     assert "x64-promotion" in _codes(out)

@@ -21,8 +21,8 @@ from repro.models import model as M
 from repro.models.transformer import RunCtx
 from repro.sharding.specs import MeshSpec
 
-from repro.compat import make_mesh
-mesh = make_mesh((2, 2), ("data", "model"))
+from repro.launch.mesh import make_host_mesh
+mesh = make_host_mesh(2, 2)
 ms = MeshSpec(mesh)
 
 # --- 1) EP relay (shard_map + all_to_all) == local scatter dispatch ------- #

@@ -188,14 +188,15 @@ def test_drain_endpoint_stops_new_traffic_mid_serve(setup, policy):
     WEIGHTED) via the datapath-visible ``ep_drained`` mask, while the
     in-flight connection keeps its slot until it completes."""
     cfg, params = setup
-    cp = _cp_pool(policy)
-    eng = interpose.Engine(cfg, I, C, max_len=32)      # nothing completes
-    loop = ServeLoop(eng, params, cp, admit_batch=2)
-    for r in range(2):                                 # one per instance
+    cp = _cp_pool(POLICY_RR)      # rr places the pre-drain pair one per
+    eng = interpose.Engine(cfg, I, C, max_len=32)      # instance; nothing
+    loop = ServeLoop(eng, params, cp, admit_batch=2)   # completes
+    for r in range(2):
         loop.submit(_req(r))
     loop.tick()
     slot = cp.endpoint_slot("pool", 1)
     assert int(np.asarray(loop.routing.ep_load)[slot]) == 1
+    cp.set_policy("pool", policy)                      # the policy under test
     cp.drain_endpoint("pool", 1)                       # loaded → masked,
     assert cp.endpoint_slot("pool", 1) == slot         # not reaped
     assert int(np.asarray(loop.routing.ep_drained)[slot]) == 1

@@ -24,7 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.compat import make_mesh
+from repro.launch.mesh import make_shard_mesh
 from repro.core import control
 from repro.core.balancer import PoolState, RequestBatch
 from repro.core.routing_table import (MAX_ENDPOINTS, MAX_EPS_PER_CLUSTER,
@@ -153,7 +153,7 @@ def test_sharded_m1_bit_exact(R, seed):
     pool = _pool(4, 3, 9)
     want = ops.admit_commit(reqs, st, pool, rnd, gum)
     got = ops.admit_commit_sharded(reqs, st, pool, rnd, gum,
-                                   mesh=make_mesh((1,), ("shard",)))
+                                   mesh=make_shard_mesh(1))
     _assert_same(want, got, f"M=1 R={R}")
     assert int(want.held) > 0          # the scenario really exercises holds
 
@@ -168,7 +168,7 @@ def test_sharded_complete_m1_bit_exact():
     want = ops.complete(pool, nxt, load, rx, ewl, ewt, eos=1,
                         max_len=max_len)
     got = ops.complete_sharded(pool, nxt, load, rx, ewl, ewt,
-                               mesh=make_mesh((1,), ("shard",)),
+                               mesh=make_shard_mesh(1),
                                eos=1, max_len=max_len)
     _assert_same(want, got, "complete M=1")
     assert int(np.asarray(want.done_cnt).sum()) > 0
@@ -179,7 +179,7 @@ def test_sharded_empty_batch_passthrough():
     reqs, rnd, gum = _batch(0, 0)
     pool = _pool(4, 3, 9)
     got = ops.admit_commit_sharded(reqs, st, pool, rnd, gum,
-                                   mesh=make_mesh((1,), ("shard",)))
+                                   mesh=make_shard_mesh(1))
     np.testing.assert_array_equal(np.asarray(got.pool.active),
                                   np.asarray(pool.active))
     np.testing.assert_array_equal(np.asarray(got.ep_load),
@@ -256,7 +256,7 @@ def test_engine_shard_validation():
         Engine(cfg, 4, 2, 8, shards=2)
     with pytest.raises(ValueError, match="mesh width"):
         Engine(cfg, 4, 2, 8, shards=2,
-               shard_mesh=make_mesh((1,), ("shard",)))
+               shard_mesh=make_shard_mesh(1))
     with pytest.raises(ValueError, match="divide"):
         Engine(cfg, 3, 2, 8, shards=2, shard_mesh=_FakeMesh())
     # pool instances not divisible over the mesh axis
@@ -304,7 +304,7 @@ os.environ["XLB_AUTOTUNE"] = "0"
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import make_mesh, shard_map
+from repro.launch.mesh import make_shard_mesh
 from repro.core import control, relay
 from repro.core.balancer import PoolState, RequestBatch
 from repro.core.routing_table import (MAX_EPS_PER_CLUSTER, N_FEATURES,
@@ -327,7 +327,7 @@ for R, seed, pad, pseed, pact, label in scenarios:
     pool = T._pool(4, 5, pseed, p_active=pact)
     want = ops.admit_commit(reqs, st, pool, rnd, gum)
     for M in (2, 4):
-        mesh = make_mesh((M,), ("shard",))
+        mesh = make_shard_mesh(M)
         got = ops.admit_commit_sharded(reqs, st, pool, rnd, gum, mesh=mesh)
         T._assert_same(want, got, f"M={M} {label}")
     print(f"sweep OK: {label} (held={int(want.held)}, "
@@ -344,7 +344,7 @@ want = ops.admit_commit(reqs, st, pool, rnd, gum)
 assert int((np.asarray(want.aff_ep) >= 0).sum()) > 0   # cache populated
 for M in (2, 4):
     got = ops.admit_commit_sharded(reqs, st, pool, rnd, gum,
-                                   mesh=make_mesh((M,), ("shard",)))
+                                   mesh=make_shard_mesh(M))
     T._assert_same(want, got, f"hash policies M={M}")
 print("sweep OK: maglev+affinity reconcile bit-exact at M in {2,4}")
 
@@ -355,7 +355,7 @@ reqs, rnd, gum = T._batch(64, 21)
 pool = T._pool(4, 5, 17)
 want = ops.admit_commit(reqs, st, pool, rnd, gum)
 got = ops.admit_commit_sharded(reqs, st, pool, rnd, gum,
-                               mesh=make_mesh((4,), ("shard",)))
+                               mesh=make_shard_mesh(4))
 T._assert_same(want, got, "fully-drained cluster")
 print("sweep OK: fully-drained cluster unroutable on every shard")
 
@@ -365,7 +365,7 @@ for I, C, seed in ((8, 6, 23), (4, 16, 29)):
     want = ops.complete(pool, nxt, load, rx, ewl, ewt, eos=1, max_len=8)
     for M in (2, 4):
         got = ops.complete_sharded(pool, nxt, load, rx, ewl, ewt,
-                                   mesh=make_mesh((M,), ("shard",)),
+                                   mesh=make_shard_mesh(M),
                                    eos=1, max_len=8)
         T._assert_same(want, got, f"complete M={M} I={I}")
     assert int(np.asarray(want.done_cnt).sum()) > 0
@@ -381,7 +381,7 @@ r = ref.admit_sharded_ref(sh(reqs.req_id), sh(reqs.svc), sh(reqs.features),
                           pool.req_id, pool.endpoint, pool.svc, pool.length,
                           pool.token, pool.active, sh(rnd), sh(gum))
 got = ops.admit_commit_sharded(reqs, st, pool, rnd, gum,
-                               mesh=make_mesh((4,), ("shard",)))
+                               mesh=make_shard_mesh(4))
 np.testing.assert_array_equal(r.slot.reshape(-1), np.asarray(got.slot))
 np.testing.assert_array_equal(r.ep_load, np.asarray(got.ep_load))
 np.testing.assert_array_equal(r.pool_active,
@@ -389,7 +389,7 @@ np.testing.assert_array_equal(r.pool_active,
 print("oracle OK: admit_sharded_ref pins the 4-shard datapath")
 
 # --- 2) sharded_apply round-trip == dense einsum oracle ------------------- #
-mesh = make_mesh((4,), ("shard",))
+mesh = make_shard_mesh(4)
 E, C, D, N = 8, 16, 4, 64
 key = jax.random.PRNGKey(0)
 x = jax.random.normal(key, (N, D), jnp.float32)
@@ -400,7 +400,7 @@ scale = jnp.arange(1.0, E + 1.0)[:, None]           # per-dest transform
 def backend(params, pool):                          # (E_loc, M*C, D)
     return pool * params[:, None, :]
 
-out_sh, meta = jax.jit(shard_map(
+out_sh, meta = jax.jit(jax.shard_map(
     lambda xx, ii, ww, pp: relay.sharded_apply(
         xx, ii, ww, n_dest=E, capacity=C, axis="shard",
         backend_fn=backend, backend_params=pp),

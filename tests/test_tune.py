@@ -2,8 +2,6 @@
 arguments beat everything, XLB_AUTOTUNE=0 never times a candidate, and a
 swept choice is cached (one sweep per (kernel, backend, shape))."""
 
-import math
-
 import pytest
 
 from repro.kernels import backend, tune
@@ -50,7 +48,7 @@ def test_autotune_off_uses_static_defaults(monkeypatch):
     assert br == tune.DEFAULT_BLOCK_R
     assert fold == backend.default_fold()
     bi, _ = tune.plan_complete((16, 256))
-    assert bi == math.gcd(16, tune.DEFAULT_BLOCK_I)
+    assert bi == min(16, tune.DEFAULT_BLOCK_I)
     # small batches clamp the default tile to the batch
     assert tune.plan_admit(32, (8, 64))[0] == 32
 
@@ -91,10 +89,48 @@ def test_sweep_picks_fastest_and_caches(monkeypatch):
     assert len(calls) > n_after_first
 
 
-def test_complete_candidates_divide_pool():
-    for I in (1, 2, 6, 8, 16, 24):
+def test_complete_candidates_divide_pool(monkeypatch):
+    """Every candidate tiles the pool (the kernel pads the last tile); for
+    a compiled backend only whole (8, 128) tiles or the whole pool are
+    offered, so I = 50 never gets 2-row blocks."""
+    for I in (1, 2, 6, 8, 16, 24, 50):
         for b in tune._complete_candidates(I):
-            assert I % b == 0 and b >= 1
+            assert 1 <= b <= I and -(-I // b) * b - I < b
+    monkeypatch.setattr(backend, "default_interpret", lambda: False)
+    for I in (1, 2, 6, 8, 16, 24, 50):
+        cands = tune._complete_candidates(I)
+        assert cands and all(b % 8 == 0 or b == I for b in cands), (I, cands)
+    assert tune._complete_candidates(50) == [8, 16, 50]
+
+
+def test_admit_candidates_tile_compiled_batches(monkeypatch):
+    """Compiled admission tiles are whole 128-lane multiples or the whole
+    batch, and fit VMEM: the interpreter-only 64-row tile and the
+    1024-row tile are never offered."""
+    monkeypatch.setattr(backend, "default_interpret", lambda: False)
+    assert tune._admit_candidates(4096) == [256]
+    assert tune._admit_candidates(1024) == [256]
+    assert tune._admit_candidates(256) == [256]
+    assert tune._admit_candidates(32) == [32]
+    monkeypatch.setattr(backend, "default_interpret", lambda: True)
+    assert tune._admit_candidates(1024) == [64, 256, 1024]
+
+
+def test_sweep_raises_on_a_failing_candidate(monkeypatch):
+    """A candidate that fails to compile aborts the sweep: it is never
+    skipped in favour of the others."""
+    monkeypatch.setenv(tune.ENV_AUTOTUNE, "1")
+
+    def make_fn(b):
+        def fn():
+            if b == 2:
+                raise RuntimeError("refused by the compiler")
+            return b
+        return fn
+
+    with pytest.raises(RuntimeError, match="refused"):
+        tune._sweep(("probe",), [1, 2, 3], make_fn)
+    assert ("probe",) not in tune._cache
 
 
 def test_fold_validation():
