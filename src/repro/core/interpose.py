@@ -152,9 +152,10 @@ class Engine:
         B = I * C
         tokens = pool.token.reshape(B, 1)
         lengths = pool.length.reshape(B)
-        logits, cache = M.decode_step(self.cfg, params, tokens, lengths,
-                                      cache, ctx=self.ctx)
-        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32).reshape(I, C)
+        with jax.named_scope("xlb_decode"):
+            logits, cache = M.decode_step(self.cfg, params, tokens, lengths,
+                                          cache, ctx=self.ctx)
+            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32).reshape(I, C)
 
         if self.shards > 1:
             res = ops.complete_sharded(
@@ -185,13 +186,16 @@ class Engine:
 
         Admission is gated by ``lax.cond`` on "any arrivals", so steady-state
         decode ticks skip the routing/allocation work entirely (the paper's
-        connect-path eBPF hook only fires on connect)."""
+        connect-path eBPF hook only fires on connect).  The gate, its
+        predicate and the whole admission branch run under the named scope
+        ``xlb_admit``; the decode runs under ``xlb_decode`` (``step``)."""
 
         @partial(jax.jit, donate_argnums=(1,) if donate else ())
         def serve_step(params, state: EngineState, reqs: RequestBatch):
-            state = jax.lax.cond(jnp.any(reqs.req_id >= 0),
-                                 lambda s: self.admit(s, reqs),
-                                 lambda s: s, state)
+            with jax.named_scope("xlb_admit"):
+                state = jax.lax.cond(jnp.any(reqs.req_id >= 0),
+                                     lambda s: self.admit(s, reqs),
+                                     lambda s: s, state)
             return self.step(params, state)
 
         from repro.analysis.invariants import sanitize_enabled

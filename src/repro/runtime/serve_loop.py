@@ -23,6 +23,7 @@ from typing import NamedTuple
 
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.analysis.invariants import assert_host, sanitize_enabled
 from repro.core import control
@@ -44,11 +45,6 @@ class Request:
     hop: int = 0                # chain position (workload/chain.py): which
     #                             service of a call chain this admission is
     tokens: list = dataclasses.field(default_factory=list)
-    # per-request tick samples (workload/slo.py): wall clocks above are
-    # advisory; these are the deterministic engine-tick measurements
-    submit_tick: int = -1       # loop tick the request entered the ingress
-    admit_tick: int = -1        # first tick it actually held a pool slot
-    done_tick: int = -1         # tick its final token completed
 
 
 class DrainReport(NamedTuple):
@@ -237,25 +233,8 @@ class ServeLoop:
 
     def submit(self, req: Request) -> None:
         req.t_submit = time.perf_counter()
-        if req.submit_tick < 0:
-            req.submit_tick = self.ticks
         self.submitted += 1
         self.queue.append(req)
-
-    def latency_samples(self) -> dict:
-        """Per-request tick samples over the completed set (workload/slo.py
-        consumes these): ``admit_to_done`` is the engine-tick service
-        latency, ``submit_to_done`` includes ingress queueing + backoff,
-        ``retries`` is the per-request hold count.  Arrays align by row."""
-        done = [r for r in self.done if r.done_tick >= 0]
-        return {
-            "req_id": np.array([r.req_id for r in done], np.int64),
-            "admit_to_done": np.array(
-                [r.done_tick - r.admit_tick for r in done], np.int64),
-            "submit_to_done": np.array(
-                [r.done_tick - r.submit_tick for r in done], np.int64),
-            "retries": np.array([r.retries for r in done], np.int64),
-        }
 
     def _backoff(self, req: Request) -> None:
         """Park a held request until its retry matures (or drop it)."""
@@ -304,56 +283,77 @@ class ServeLoop:
 
     # ------------------------------------------------------------------ #
     def tick(self) -> dict:
-        """One engine step: admit waiting requests + decode every lane."""
-        if self.cp is not None:
-            self.cp.heartbeat(self)          # liveness lease (core/control)
-        elif self.remote is not None:        # transport-attached: plans in,
-            self.remote.pump(self.ticks)     # heartbeat + load report out
-        if self.fault is not None:           # injected faults roll progress
-            pool = self.fault.apply(self.state.pool, self.ticks)
-            if pool is not self.state.pool:  # back BEFORE the step so a
-                self.state = self.state._replace(pool=pool)  # held slot
-        self._release_matured()              # can't complete this tick
-        reqs, taken = self._next_admission()
-        self.state, out = self.serve_step(self.params, self.state, reqs)
-        emitted = np.asarray(out["emitted"])
-        done = np.asarray(out["done"])
-        ids = np.asarray(out["req_id"])          # ids serviced this tick
-        I, C = emitted.shape
-        serviced = set()
-        for i in range(I):
-            for s in range(C):
-                rid = int(ids[i, s])
-                if rid >= 0 and rid in self.inflight:
-                    serviced.add(rid)
-                    req = self.inflight[rid]
-                    if req.admit_tick < 0:    # first tick holding a slot
-                        req.admit_tick = self.ticks
-                    req.tokens.append(int(emitted[i, s]))
-                    if done[i, s]:
-                        r = self.inflight.pop(rid)
-                        r.t_done = time.perf_counter()
-                        r.done_tick = self.ticks
-                        self.done.append(r)
-        # held requests (pool exhausted / unroutable this tick) re-queue —
-        # the paper's bounded hold queue lives on the host ingress
-        for r in taken:
-            if r.req_id not in serviced and r.req_id in self.inflight:
-                self.inflight.pop(r.req_id)
-                if r.retries == 0:          # first hold: count the REQUEST
-                    self.held_first += 1    # (attempts land in overflow)
-                r.retries += 1
-                self._backoff(r)            # park (or drop at max_retries);
-                #                             submitted == done + dropped +
-                #                             n_queued + inflight throughout
-        self.ticks += 1
-        if sanitize_enabled():
-            assert_host("loop", dict(
-                submitted=self.submitted, done=len(self.done),
-                dropped=len(self.dropped), queued=self.n_queued,
-                inflight=len(self.inflight)))
-        return {"active": int(out["active"]), "queued": self.n_queued,
-                "done": len(self.done), "dropped": len(self.dropped)}
+        """One engine step: admit waiting requests + decode every lane.
+
+        Five host spans cover the tick end to end, one after another, in
+        the profiler's trace (on the device planes' clock when a profiler
+        session runs; about a microsecond each when none does):
+        ``loop.control``, ``loop.admission`` (metadata ``rows`` taken into
+        the batch, ``batch`` its width), ``loop.dispatch`` (the
+        ``serve_step`` call), ``loop.readback`` (every blocking
+        device→host read) and ``loop.bookkeeping`` (metadata ``held``:
+        rows taken but not serviced, re-queued or dropped).  The return
+        carries the same ``taken`` and ``held`` counts."""
+        with TraceAnnotation("loop.control"):
+            if self.cp is not None:
+                self.cp.heartbeat(self)          # liveness lease
+            elif self.remote is not None:        # transport-attached: plans
+                self.remote.pump(self.ticks)     # in, heartbeat + load out
+            # injected faults roll progress back BEFORE the step, so a held
+            # slot can't complete this tick
+            if self.fault is not None:
+                pool = self.fault.apply(self.state.pool, self.ticks)
+                if pool is not self.state.pool:
+                    self.state = self.state._replace(pool=pool)
+        with TraceAnnotation("loop.admission") as span:
+            self._release_matured()
+            reqs, taken = self._next_admission()
+            span.set_metadata(rows=len(taken), batch=self.admit_batch)
+        with TraceAnnotation("loop.dispatch"):
+            self.state, out = self.serve_step(self.params, self.state, reqs)
+        with TraceAnnotation("loop.readback"):
+            emitted = np.asarray(out["emitted"])
+            done = np.asarray(out["done"])
+            ids = np.asarray(out["req_id"])      # ids serviced this tick
+            active = int(out["active"])
+        with TraceAnnotation("loop.bookkeeping") as span:
+            I, C = emitted.shape
+            serviced = set()
+            for i in range(I):
+                for s in range(C):
+                    rid = int(ids[i, s])
+                    if rid >= 0 and rid in self.inflight:
+                        serviced.add(rid)
+                        req = self.inflight[rid]
+                        req.tokens.append(int(emitted[i, s]))
+                        if done[i, s]:
+                            r = self.inflight.pop(rid)
+                            r.t_done = time.perf_counter()
+                            self.done.append(r)
+            # held requests (pool exhausted / unroutable this tick)
+            # re-queue — the paper's bounded hold queue lives on the host
+            # ingress
+            held = 0
+            for r in taken:
+                if r.req_id not in serviced and r.req_id in self.inflight:
+                    self.inflight.pop(r.req_id)
+                    held += 1
+                    if r.retries == 0:       # first hold: count the REQUEST
+                        self.held_first += 1  # (attempts land in overflow)
+                    r.retries += 1
+                    self._backoff(r)         # park (or drop at max_retries);
+                    #                          submitted == done + dropped +
+                    #                          n_queued + inflight throughout
+            span.set_metadata(held=held)
+            self.ticks += 1
+            if sanitize_enabled():
+                assert_host("loop", dict(
+                    submitted=self.submitted, done=len(self.done),
+                    dropped=len(self.dropped), queued=self.n_queued,
+                    inflight=len(self.inflight)))
+        return {"active": active, "queued": self.n_queued,
+                "done": len(self.done), "dropped": len(self.dropped),
+                "taken": len(taken), "held": held}
 
     def drain(self, max_ticks: int = 10_000) -> DrainReport:
         """Tick until idle (or the budget runs out) and report everything —

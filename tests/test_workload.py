@@ -359,47 +359,3 @@ def test_replay_depth3_chain_with_midrun_canary():
                         args={"instance": 1, "pct": 75.0})])
     assert r["ops"] == 1 and r["txns"] == 1
     assert r["completed"] == 8
-
-
-# --------------------------------------------------------------------------- #
-# ServeLoop latency samples (S1)
-# --------------------------------------------------------------------------- #
-
-
-def test_serve_loop_records_latency_samples():
-    """The runtime loop itself carries per-request tick samples: submit →
-    first admitted tick → completion tick, plus the retry count."""
-    import jax
-    import jax.numpy as jnp
-
-    from repro.configs import get_config, smoke_config
-    from repro.core import interpose
-    from repro.models import model as M
-    from repro.runtime.serve_loop import Request, ServeLoop
-
-    cfg = smoke_config(get_config("xlb-service-model"))
-    params = M.init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
-    cp = _cp(2, policy=POLICY_RR)
-    eng = interpose.Engine(cfg, 2, 4, max_len=3, eos=-1)  # length-driven
-    loop = ServeLoop(eng, params, cp, admit_batch=4)
-    for r in range(6):
-        loop.submit(Request(req_id=r, service=0, headers={},
-                            prompt_token=3 + r))
-    rep = loop.drain(max_ticks=60)
-    assert len(rep.done) == 6
-    s = loop.latency_samples()
-    assert sorted(s["req_id"].tolist()) == list(range(6))
-    assert (s["admit_to_done"] >= 0).all()
-    # queueing (submit → admit) can only add latency
-    assert (s["submit_to_done"] >= s["admit_to_done"]).all()
-    assert (s["retries"] >= 0).all()
-    # samples are ticks, not wall time: replaying gives identical arrays
-    loop2 = ServeLoop(interpose.Engine(cfg, 2, 4, max_len=3, eos=-1),
-                      params, _cp(2, policy=POLICY_RR), admit_batch=4)
-    for r in range(6):
-        loop2.submit(Request(req_id=r, service=0, headers={},
-                             prompt_token=3 + r))
-    loop2.drain(max_ticks=60)
-    s2 = loop2.latency_samples()
-    for k in s:
-        assert np.array_equal(s[k], s2[k]), k
