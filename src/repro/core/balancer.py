@@ -18,6 +18,11 @@ written once against the seam and never against an engine:
 ``step``/``serve_step`` return an ``out`` dict with the same keys for every
 engine: ``emitted``/``done``/``req_id`` as (I, C) arrays over the connection
 pool and an ``active`` count — the host driver never branches on the mode.
+``wire`` packs all four into one int32 vector of length ``3·I·C + 1``:
+``emitted``, ``done`` (0/1) and ``req_id``, each raveled row-major over
+(I, C), then ``active``.  ``ServeLoop`` reads only ``wire``, so a tick
+costs one device→host read; ``I`` and ``C`` are the engine's
+``n_instances`` and ``slots``.
 
 The shared wire types live here too: ``RequestBatch`` (host-ingress output)
 and ``PoolState`` (per-(instance, slot) connection state).  They are plain
@@ -70,6 +75,9 @@ class PoolState(NamedTuple):
 @runtime_checkable
 class Balancer(Protocol):
     """Structural type every serving engine implements (XLB/Istio/Cilium)."""
+
+    n_instances: int      # I of the (I, C) pool
+    slots: int            # C
 
     def init_state(self, routing, dtype=None) -> Any:
         """Build the engine state for one fleet around a routing snapshot."""

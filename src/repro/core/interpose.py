@@ -175,9 +175,15 @@ class Engine:
                                         ep_inflight_ewma=res.ep_inflight_ewma,
                                         ep_tput_ewma=res.ep_tput_ewma)
         metrics = state.metrics._replace(rx_bytes=res.rx_bytes)
+        active = res.pool.active.sum(dtype=jnp.int32)
         out = {"emitted": nxt, "done": res.done,
                "req_id": state.pool.req_id,     # ids that produced this tick
-               "active": res.pool.active.sum()}
+               "active": active,
+               # all four packed in one vector: the host pays one
+               # device→host transfer a tick instead of four
+               "wire": jnp.concatenate([
+                   nxt.ravel(), res.done.astype(jnp.int32).ravel(),
+                   state.pool.req_id.ravel(), active[None]])}
         return EngineState(rstate, res.pool, cache, metrics, state.key), out
 
     # ------------------------------------------------------------------ #

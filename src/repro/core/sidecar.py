@@ -256,8 +256,12 @@ class SidecarEngine:
         pool.req_id[done] = -1
         pool.endpoint[done] = -1
         pool.length[done] = 0
+        active = int(act.sum() - done.sum())
         out = {"emitted": nxt, "done": done, "req_id": pre_req,
-               "active": int(act.sum() - done.sum())}
+               "active": active,
+               "wire": np.concatenate([
+                   nxt.ravel(), done.astype(np.int32).ravel(),
+                   pre_req.ravel(), np.array([active], np.int32)])}
         return state, out
 
     # ------------------------------------------------------------------ #

@@ -290,10 +290,12 @@ class ServeLoop:
         session runs; about a microsecond each when none does):
         ``loop.control``, ``loop.admission`` (metadata ``rows`` taken into
         the batch, ``batch`` its width), ``loop.dispatch`` (the
-        ``serve_step`` call), ``loop.readback`` (every blocking
-        device→host read) and ``loop.bookkeeping`` (metadata ``held``:
-        rows taken but not serviced, re-queued or dropped).  The return
-        carries the same ``taken`` and ``held`` counts."""
+        ``serve_step`` call), ``loop.readback`` (one blocking device→host
+        read of the step's packed ``wire``; metadata ``reads``, the reads
+        issued, and ``bytes``, their size) and ``loop.bookkeeping``
+        (metadata ``held``: rows taken but not serviced, re-queued or
+        dropped).  The return carries the same ``taken`` and ``held``
+        counts."""
         with TraceAnnotation("loop.control"):
             if self.cp is not None:
                 self.cp.heartbeat(self)          # liveness lease
@@ -311,13 +313,14 @@ class ServeLoop:
             span.set_metadata(rows=len(taken), batch=self.admit_batch)
         with TraceAnnotation("loop.dispatch"):
             self.state, out = self.serve_step(self.params, self.state, reqs)
-        with TraceAnnotation("loop.readback"):
-            emitted = np.asarray(out["emitted"])
-            done = np.asarray(out["done"])
-            ids = np.asarray(out["req_id"])      # ids serviced this tick
-            active = int(out["active"])
+        with TraceAnnotation("loop.readback") as span:
+            wire = np.asarray(out["wire"])       # the tick's one read
+            span.set_metadata(reads=1, bytes=wire.nbytes)
+            I, C = self.balancer.n_instances, self.balancer.slots
+            # views of the packed vector; ids: those serviced this tick
+            emitted, done, ids = wire[:-1].reshape(3, I, C)
+            active = int(wire[-1])
         with TraceAnnotation("loop.bookkeeping") as span:
-            I, C = emitted.shape
             serviced = set()
             for i in range(I):
                 for s in range(C):

@@ -1,6 +1,6 @@
 """The serving loop's own trace: five phase spans a tick with their
-counters, the same counts in ``tick()``'s return, and the named scopes of
-``serve_step``.
+counters (the one read a tick on ``loop.readback`` among them), the same
+counts in ``tick()``'s return, and the named scopes of ``serve_step``.
 
 Every test that starts the profiler lives in this one file, so that one
 test worker holds it at a time."""
@@ -91,6 +91,8 @@ def test_each_tick_holds_the_five_phase_spans_in_order(traced_run):
             assert 0 <= meta["rows"] <= ADMIT_BATCH
         elif name == "loop.bookkeeping":
             assert set(meta) == {"held"}
+        elif name == "loop.readback":
+            assert set(meta) == {"reads", "bytes"}
         else:
             assert meta == {}
 
@@ -104,6 +106,14 @@ def test_span_counters_are_the_ticks_returns(traced_run):
     assert [m["held"] for m in meta["loop.bookkeeping"]] == [
         o["held"] for o in outs]
     assert sum(o["held"] for o in outs) > 0      # the run holds
+
+
+def test_readback_is_one_read_of_the_packed_outputs(traced_run):
+    """Each tick reads the step's outputs once: ``emitted``, ``done`` and
+    ``req_id`` over the 2 x 2 pool and ``active``, as 13 int32 words."""
+    n, _, events = traced_run
+    meta = [m for name, _, _, m in events if name == "loop.readback"]
+    assert meta == [{"reads": 1, "bytes": 4 * (3 * 2 * 2 + 1)}] * n
 
 
 def test_counters_match_the_engines_flow_metrics(cfg_params):
