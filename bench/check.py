@@ -20,8 +20,9 @@ which depend on the application, in the configuration file's
   finished nor dropped after the grace period (exact: 0).
 * ``logit_gap``: over a seeded sample of the window's finished requests
   (the longest among them), the widest gap by which a served token's
-  reference logit lies below the reference's best, the reference run in
-  float32 at the highest precision on the prompt and the served tokens.
+  reference logit lies below the reference's best, the application's
+  reference (``bench/apps/<name>.py``) run in float32 at the highest
+  precision on the prompt and the served tokens.
 
 The control (``control_numbers``; ``bench/run.py::execute`` with
 ``control=True``, read by ``bench/control.py``) puts the reference in the
@@ -145,18 +146,19 @@ def decode_batch(reqs: list, max_len: int) -> tuple[np.ndarray, np.ndarray]:
     return inp, srv
 
 
-def logit_gaps(app: dict, params, reqs: list, max_len: int,
+def logit_gaps(ref, app: dict, params, reqs: list, max_len: int,
                dtype=jnp.float32) -> np.ndarray:
-    """Gaps of the served tokens under the float32 reference; with a lower
-    ``dtype`` the gaps of the tokens that precision puts first instead
-    (the control, read at the same positions)."""
+    """Gaps of the served tokens under the application's float32 reference
+    ``ref`` (a ``bench/apps`` module); with a lower ``dtype`` the gaps of
+    the tokens that precision puts first instead (the control, read at the
+    same positions)."""
     inp, srv = decode_batch(reqs, max_len)
-    ref = reference.forward_logits(app, params, jnp.asarray(inp))
+    logits = ref.forward_logits(app, params, jnp.asarray(inp))
     if dtype == jnp.float32:
-        return reference.served_gaps(ref, srv)
-    low = reference.forward_logits(app, params, jnp.asarray(inp), dtype)
+        return reference.served_gaps(logits, srv)
+    low = ref.forward_logits(app, params, jnp.asarray(inp), dtype)
     first = np.asarray(jnp.argmax(low, -1))
-    return reference.served_gaps(ref, srv, first_choice=first)
+    return reference.served_gaps(logits, srv, first_choice=first)
 
 
 def run_checks(dep, log, samples: list, done: list, seed: int) -> dict:
@@ -189,7 +191,8 @@ def run_checks(dep, log, samples: list, done: list, seed: int) -> dict:
         not length_ok(r.tokens, eos, max_len) for r in in_win)
     numbers["unfinished"] = int(np.sum(np.isnan(log.seen) & ~log.dropped))
     sample = decode_sample(in_win, seed)
-    gaps = logit_gaps(cfg["application"], dep.params, sample, max_len)
+    gaps = logit_gaps(dep.app_ref, cfg["application"], dep.params,
+                      sample, max_len)
     numbers["logit_gap"] = float(gaps.max(initial=0.0))
     return {k: (v, lim[k]) for k, v in numbers.items()}
 
@@ -204,7 +207,7 @@ def control_numbers(dep, log, done: list, seed: int,
     app = dep.config["application"]
     win = log.in_window()
     sample = decode_sample([r for r in done if win[r.req_id]], seed)
-    gaps = logit_gaps(app, dep.params, sample, dep.max_len,
+    gaps = logit_gaps(dep.app_ref, app, dep.params, sample, dep.max_len,
                       dtype=CONTROL[app["dtype"]])
     out = dict(numbers)
     out["logit_gap"] = (float(gaps.max(initial=0.0)),

@@ -4,10 +4,11 @@ weights, the routing through ``ControlPlane``, the engine and the
 
 The configuration file (``bench/configs/<name>.json``) is data: engine,
 fleet, the engine's kernel tiles, services with their rules, clusters
-with their policies, and the application with its published sizes and
-the dtype it is served in.  Tiles stated in the file are passed to the
-engine, so that no run sweeps for them and every run serves the same
-compiled program.
+with their policies, and the application with its published sizes, the
+dtype it is served in and the reference it is checked against
+(``reference``, a module of ``bench/apps``; ``dense`` where it names
+none).  Tiles stated in the file are passed to the engine, so that no
+run sweeps for them and every run serves the same compiled program.
 """
 
 from __future__ import annotations
@@ -20,26 +21,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench import reference
-
-# Application sizes the configuration states, checked against the
-# program's registered model so that the reference and the program run
-# the same architecture.
-APP_KEYS = ("n_layers", "d_model", "n_heads", "n_kv_heads", "head_dim",
-            "d_ff", "vocab", "ffn_act", "norm_eps", "rope_theta")
+from bench import apps, reference, registry
 
 
 def load_json(path: Path) -> dict:
     with open(path) as f:
         return json.load(f)
-
-
-def check_application(app: dict, cfg) -> None:
-    """The configuration's application entry must be the program's model."""
-    bad = [k for k in APP_KEYS if app[k] != getattr(cfg, k)]
-    if bad or cfg.family != "dense" or cfg.moe.n_experts or cfg.is_encdec:
-        raise ValueError(f"application {app['name']!r} differs from the "
-                         f"program's model in {bad or ['family']}")
 
 
 def param_shapes(cfg, dtype):
@@ -82,14 +69,17 @@ class Deployment:
     loop: object             # ServeLoop
     service_ids: dict        # service name → id the loop routes on
     tables: reference.RefTables
+    app_ref: object          # the application's reference (``bench/apps``)
 
     @property
     def n_slots(self) -> int:
         return self.config["instances"] * self.config["slots"]
 
 
-def build(config: dict, max_len: int, seed: int) -> Deployment:
-    """Boot the configuration's fleet with weights from ``seed``."""
+def build(config: dict, max_len: int, seed: int,
+          root: Path = registry.ROOT) -> Deployment:
+    """Boot the configuration's fleet with weights from ``seed``; the
+    application's reference is ``root``'s (``bench/apps``)."""
     from repro.configs import get_config
     from repro.core.balancer import make_balancer
     from repro.core.control import ControlPlane
@@ -99,7 +89,8 @@ def build(config: dict, max_len: int, seed: int) -> Deployment:
 
     app = config["application"]
     cfg = get_config(app["name"])
-    check_application(app, cfg)
+    ref = apps.for_application(app, root)
+    ref.check(app, cfg)
     dtype = jnp.dtype(app["dtype"])
     params = make_params(param_shapes(cfg, dtype), seed)
     fields = reference.HEADER_FIELDS
@@ -122,4 +113,4 @@ def build(config: dict, max_len: int, seed: int) -> Deployment:
         config, int(loop.state.routing.maglev_table.shape[1]))
     return Deployment(config, max_len, cfg, params, loop,
                       {s["name"]: i for i, s in enumerate(config["services"])},
-                      tables)
+                      tables, ref)

@@ -1,4 +1,5 @@
-"""Plain reference for one XLB serving step, independent of the program.
+"""Plain reference for the balancer's part of one XLB serving step,
+independent of the program.
 
 It restates, from the program's documented semantics, what ``serve_step``
 must do, and imports nothing of ``repro``:
@@ -11,10 +12,8 @@ must do, and imports nothing of ``repro``:
   commit, held release at the end of the batch, flow metrics.
 * ``complete``: the completion kernel as a loop over the pool: done on
   EOS or on the length budget, load release, rx bytes, latency EWMAs.
-* ``forward_logits``: the application's full causal forward over a whole
-  sequence (embedding, RMSNorm, rotary GQA attention, SwiGLU or GELU
-  FFN, head) in float32 at the highest matmul precision, or in a lower
-  precision for the control.
+* ``served_gaps``: how far each served token's logit lies below the best
+  of the application's reference (``bench/apps/<name>.py``).
 
 Array sizes are read from the arrays the step was given, never from the
 program's constants.
@@ -375,79 +374,6 @@ def complete(state: dict, nxt: np.ndarray, *, eos: int, max_len: int
         ep_inflight_ewma=ewma(ewl, occ, ALPHA_INFLIGHT),
         ep_tput_ewma=ewma(ewt, cnt, ALPHA_TPUT))
     return out
-
-
-# --------------------------------------------------------------------------- #
-# the application: a full-sequence forward
-# --------------------------------------------------------------------------- #
-
-
-def _rms(x, scale, eps):
-    var = jnp.mean(jnp.square(x.astype(jnp.float32)), -1, keepdims=True)
-    y = x.astype(jnp.float32) * jax.lax.rsqrt(var + eps)
-    return (y * scale.astype(jnp.float32)).astype(x.dtype)
-
-
-def _rope(x, theta):
-    """x: (B, S, H, hd); rotate-half rotary embedding at positions 0..S-1."""
-    hd = x.shape[-1]
-    freqs = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
-    ang = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * freqs
-    cos, sin = jnp.cos(ang)[None, :, None], jnp.sin(ang)[None, :, None]
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                           -1).astype(x.dtype)
-
-
-def forward_logits(app: dict, params: dict, tokens, dtype=jnp.float32):
-    """Logits (B, S, V) of a causal forward over ``tokens`` (B, S).  The
-    application is described by the configuration's ``application``
-    entry.  ``dtype`` is the precision it is computed in: float32 at the
-    highest matmul precision; bfloat16; or float8_e4m3fn, every weight and
-    every matmul operand rounded to fp8, products accumulated in float32
-    and activations carried in bfloat16."""
-    H, K, hd = app["n_heads"], app["n_kv_heads"], app["head_dim"]
-    eps, theta = app["norm_eps"], app["rope_theta"]
-    act = jnp.bfloat16 if dtype == jnp.float8_e4m3fn else dtype
-
-    def rnd(a):
-        return a.astype(dtype).astype(act)
-
-    def mm(eq, a, b):
-        return jnp.einsum(eq, rnd(a), rnd(b),
-                          preferred_element_type=jnp.float32)
-
-    p = jax.tree.map(rnd, params)
-    B, S = tokens.shape
-    G = H // K
-    mask = jnp.tril(jnp.ones((S, S), bool))
-    prec = "highest" if dtype == jnp.float32 else "default"
-    with jax.default_matmul_precision(prec):
-        x = p["embed"][tokens]
-        for li in range(app["n_layers"]):
-            lp = jax.tree.map(lambda a: a[li], p["blocks"])
-            at, f = lp["attn"], lp["ffn"]
-            h = _rms(x, lp["norm1"], eps)
-            q = mm("bsd,de->bse", h, at["wq"]).astype(act)
-            k = mm("bsd,de->bse", h, at["wk"]).astype(act)
-            v = mm("bsd,de->bse", h, at["wv"]).astype(act)
-            q = _rope(q.reshape(B, S, H, hd), theta).reshape(B, S, K, G, hd)
-            k = _rope(k.reshape(B, S, K, hd), theta)
-            s = mm("bqkgh,bskh->bkgqs", q, k)
-            s = jnp.where(mask, s / np.sqrt(hd).astype(np.float32), -1e30)
-            w = jax.nn.softmax(s, axis=-1)
-            o = mm("bkgqs,bskh->bqkgh", w, v.reshape(B, S, K, hd))
-            o = o.reshape(B, S, H * hd).astype(act)
-            x = x + mm("bsd,de->bse", o, at["wo"]).astype(act)
-            h = _rms(x, lp["norm2"], eps)
-            u = mm("bsd,df->bsf", h, f["w_in"])
-            if app["ffn_act"] == "swiglu":
-                u = jax.nn.silu(mm("bsd,df->bsf", h, f["w_gate"])) * u
-            else:
-                u = jax.nn.gelu(u)
-            x = x + mm("bsf,fd->bsd", u.astype(act), f["w_out"]).astype(act)
-        x = _rms(x, p["norm_f"], eps)
-        return mm("bsd,dv->bsv", x, p["head"])
 
 
 def served_gaps(logits, served, first_choice=None) -> np.ndarray:

@@ -6,8 +6,10 @@
 
 A cell of ``BENCHMARK.json`` names a configuration
 (``bench/configs/<config>.json``) and a traffic mix
-(``bench/traffic/<mix>.json``); its per-layer metrics are read by
-``bench/metrics/<metric>.py``.  The run:
+(``bench/traffic/<mix>.json``); the configuration's application is
+checked against ``bench/apps/<reference>.py``, each kernel's cost counted
+by ``bench/costs/<kernel>.py`` and each per-layer metric read by
+``bench/metrics/<metric>.py`` (``bench/registry.py``).  The run:
 
 1. fails, printing no result, unless JAX's first device is a TPU and the
    cell's chips are there;
@@ -16,8 +18,10 @@ A cell of ``BENCHMARK.json`` names a configuration
    mix's own traffic;
 3. measures for ``--seconds`` (``bench/driver.py``), with the profiler on
    over a few steady seconds when ``--trace 1``;
-4. reads the device's peak memory, drops the program's state, and decides
-   ``correct`` against the plain reference (``bench/check.py``);
+4. reads the device's peak memory, with ``--trace 1`` reads the named
+   scopes from the text of the ``serve_step`` module that ran, drops the
+   program's state, and decides ``correct`` against the plain reference
+   (``bench/check.py``);
 5. prints the numbers compared as the last lines of standard error, and
    one JSON object as the last line of standard output.
 
@@ -33,7 +37,6 @@ T_PROCESS = time.perf_counter()
 
 import argparse  # noqa: E402
 import dataclasses  # noqa: E402
-import importlib  # noqa: E402
 import json  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
@@ -151,12 +154,15 @@ class LayerRun:
     peaks: dict
     costs: dict               # kernel → (ops, bytes) per launch
     step_flops: float         # decode FLOPs per tick
+    phases: object = None     # bench.phases.PhaseSummary: the program's
+    #                           spans, counters and scopes
 
 
-def per_layer(metrics: list, run: LayerRun) -> dict:
+def per_layer(metrics: list, run: LayerRun, root: Path = ROOT) -> dict:
+    from bench import registry
     out = {}
     for m in metrics:
-        v = importlib.import_module(f"bench.metrics.{m['name']}").read(run)
+        v = registry.load("metrics", m["name"], root).read(run)
         if v is not None:
             out[m["name"]] = {"value": float(v), "unit": m["unit"]}
     return out
@@ -181,7 +187,7 @@ def execute(workload: str, seed: int, seconds: float, trace: bool, *,
     extracted trace events."""
     import numpy as np
 
-    from bench import check, counts, deploy, driver, peaks, traffic
+    from bench import check, costs, deploy, driver, peaks, phases, traffic
     from bench import trace as tr
     cell = load_cell(workload, root)
     jax = setup_jax()
@@ -189,7 +195,7 @@ def execute(workload: str, seed: int, seconds: float, trace: bool, *,
     chip_peaks = peaks.peaks_for(device["kind"]) if require_chip else None
     mix = traffic.Mix(cell.mix, seed, cell.config["application"]["vocab"])
     t_build = time.perf_counter()
-    dep = deploy.build(cell.config, mix.max_len, seed)
+    dep = deploy.build(cell.config, mix.max_len, seed, root)
     trace_dir = OUT / "trace" / f"{workload}-{seed}" if trace else None
     if trace_dir is not None:
         shutil.rmtree(trace_dir, ignore_errors=True)
@@ -206,7 +212,17 @@ def execute(workload: str, seed: int, seconds: float, trace: bool, *,
         f"{run.win_start - t_traffic:.3f}")
     device["memory_peak_bytes"] = memory_peak(jax)
     loop = dep.loop
-    costs = counts.kernel_shapes(loop.state, loop.admit_batch)
+    kernel_costs = costs.per_launch(loop.state, loop.params,
+                                    cell.config["application"],
+                                    loop.admit_batch, root)
+    log(f"kernel costs (operations, bytes a launch): {kernel_costs}")
+    names = {}                            # instruction → named scope
+    if trace and drv.rec.samples:
+        t_scopes = time.perf_counter()
+        names = phases.scope_names(phases.compiled_text(
+            drv.rec.step, loop.params, loop.state, drv.rec.samples[0][1]))
+        log(f"scopes: serve_step's module text read in "
+            f"{time.perf_counter() - t_scopes:.3f} s")
     m = loop.state.metrics
     log(f"flow metrics (per admission attempt): requests "
         f"{int(m.requests.sum())}, overflow {int(m.overflow)}, no route "
@@ -243,7 +259,8 @@ def execute(workload: str, seed: int, seconds: float, trace: bool, *,
                                          "unit": m["unit"]}
                              for m in cell.end_to_end if m["name"] in e2e}
     else:
-        ev = tr.extract(tr.newest_xplane(trace_dir))
+        ev = tr.extract(tr.newest_xplane(trace_dir), names,
+                        tuple(kernel_costs))
         if dump is not None:
             dump.mkdir(parents=True, exist_ok=True)
             (dump / f"{workload}-{seed}.json").write_text(json.dumps(ev))
@@ -251,15 +268,19 @@ def execute(workload: str, seed: int, seconds: float, trace: bool, *,
         summary = tr.reduce(ev)
         if summary is None:
             raise RuntimeError("the trace holds no tick span or no device")
-        lr = LayerRun(summary, chip_peaks, costs, counts.decode_flops(
-            cell.config["application"], dep.n_slots, dep.max_len))
-        result["metrics"] = per_layer(cell.per_layer, lr)
+        lr = LayerRun(summary, chip_peaks, kernel_costs,
+                      dep.app_ref.decode_flops(cell.config["application"],
+                                                 dep.n_slots, dep.max_len),
+                      phases.reduce(ev))
+        result["metrics"] = per_layer(cell.per_layer, lr, root)
         device["busy_s"] = summary.busy_s
         device["window_s"] = summary.window_s
         result["breakdown"] = {"device_ops": summary.top_ops,
                                "idle_gaps": summary.idle_gaps}
         log(f"trace: {summary.n_ticks} ticks, {summary.n_steps} serve_step "
-            f"runs, kernels {summary.kernels}")
+            f"runs, kernels {summary.kernels}, "
+            f"{sum(o[0] not in names for o in ev['ops'])} of "
+            f"{len(ev['ops'])} ops not in the compiled module")
     result["device"] = device
     result["checks"] = {k: {"value": v, "limit": lim}
                         for k, (v, lim) in numbers.items()}

@@ -27,13 +27,17 @@ def half_batch(step):
 
 
 def token_altered(step):
+    """The decode's token altered where it is produced: in the pool's next
+    token, in ``emitted`` and in ``wire``, the packed vector the loop
+    serves from (``emitted`` its first I x C words)."""
     def broken(params, state, reqs):
         new, out = step(params, state, reqs)
         act = new.pool.active
         tok = jnp.where(act, (new.pool.token + 7) % 512, new.pool.token)
-        out = dict(out, emitted=jnp.where(state.pool.active | act,
-                                          (out["emitted"] + 7) % 512,
-                                          out["emitted"]))
+        emitted = jnp.where(state.pool.active | act,
+                            (out["emitted"] + 7) % 512, out["emitted"])
+        out = dict(out, emitted=emitted,
+                   wire=out["wire"].at[:emitted.size].set(emitted.ravel()))
         return new._replace(pool=new.pool._replace(token=tok)), out
     return broken
 

@@ -6,7 +6,7 @@ from pathlib import Path
 
 import pytest
 
-from bench import phases, trace
+from bench import phases, registry, run, trace
 
 DATA = Path(__file__).resolve().parent / "data"
 
@@ -104,7 +104,6 @@ def test_readings_on_hand_made_events():
                  "bookkeeping_ms": pytest.approx(33e-9 / 2 * 1e3),
                  "admit_scope_us": pytest.approx(20e-9 / 2 * 1e6),
                  "decode_us": pytest.approx(50e-9 / 2 * 1e6),
-                 "admit_fill_pct": pytest.approx(100 * 3 / 8),
                  "held_pct": pytest.approx(100 * 1 / 3)}
 
 
@@ -153,6 +152,26 @@ def test_scopes_are_named_from_the_compiled_module():
                      "while.2": "xlb_decode", "copy.176": None,
                      "dot.1": None, "fusion.9": "xlb_decode",
                      "xlb_complete.1": None}
+
+
+def test_a_new_scope_or_phase_is_read_without_an_edit():
+    """Any ``xlb_*`` scope is named, the outermost on the path; any
+    ``loop.*`` span is a phase, and names the idle time under it."""
+    hlo = ('  %fusion.4 = f32[8]{0} fusion(f32[8]{0} %x), metadata={op_name='
+           '"jit(serve_step)/xlb_decode/xlb_moe/dot"}\n'
+           '  %fusion.5 = f32[8]{0} fusion(f32[8]{0} %x), metadata={op_name='
+           '"jit(serve_step)/xlb_moe"}\n')
+    assert phases.scope_names(hlo) == {"fusion.4": "xlb_decode",
+                                       "fusion.5": None}
+    ev = _events()
+    ev["phases"].append(["loop.relay", 95, 5, {"experts": 2}])
+    s = phases.reduce(ev)
+    assert s.phases["loop.relay"] == {"count": 1,
+                                      "seconds": pytest.approx(5e-9)}
+    gaps = dict(s.idle_gaps)
+    # the relay takes the 95-100 ns that the first tick alone held
+    assert gaps["loop.relay"] == pytest.approx(5e-9)
+    assert gaps["tick"] == pytest.approx(2e-9)
 
 
 def test_the_harness_recorded_trace_reduces_as_before():
@@ -236,10 +255,43 @@ def test_recorded_phases_summary_and_the_harness_reading():
             assert s.phases[n]["seconds"] == pytest.approx(v["seconds"])
         assert s.scopes == pytest.approx(want["scopes"])
         assert s.counters == want["counters"]
-        for k in trace.KERNELS:
+        for k in ("xlb_admit_commit", "xlb_complete"):
             assert base.kernels[k]["launches"] == s.n_ticks
             assert base.kernels[k]["seconds"] == pytest.approx(
                 want["kernels"][k]["seconds"])
         gaps = dict(s.idle_gaps)
         assert sum(gaps.values()) == pytest.approx(s.window_s - s.busy_s)
         assert gaps.get("tick", 0.0) < 0.1 * dict(base.idle_gaps)["tick"]
+
+
+READINGS = ("admission_ms", "dispatch_ms", "readback_ms", "bookkeeping_ms",
+            "held_pct", "admit_scope_us", "decode_us")
+
+
+@pytest.mark.parametrize("name", READINGS)
+def test_each_reading_has_its_reader(name):
+    """The per-layer reader of each reading returns what ``readings`` gives
+    on the recorded trace with the program's spans, and nothing on the
+    one without."""
+    for rec in _recorded():
+        ev = rec["events"]
+        s = phases.reduce(ev)
+        lr = run.LayerRun(trace.reduce(ev), None, {}, 0.0, s)
+        got = registry.load("metrics", name).read(lr)
+        assert got is not None
+        assert got == phases.readings(s)[name]
+    old = json.loads(sorted(DATA.glob("trace-*.json"))[0].read_text())
+    ev = old["events"]
+    lr = run.LayerRun(trace.reduce(ev), None, {}, 0.0, phases.reduce(ev))
+    assert registry.load("metrics", name).read(lr) is None
+    assert registry.load("metrics", name).read(
+        run.LayerRun(trace.reduce(ev), None, {}, 0.0)) is None
+
+
+def test_the_readings_are_per_layer_metrics_of_every_cell():
+    bm = json.loads((run.ROOT / "BENCHMARK.json").read_text())
+    entries = {m["name"]: m for m in bm["per_layer"]}
+    for name in READINGS:
+        assert "workloads" not in entries[name]
+        assert entries[name]["moves"] == "p50_latency_ms"
+    assert set(phases.readings(phases.reduce(_events()))) == set(READINGS)

@@ -1,4 +1,4 @@
-"""The plain reference agrees with the program's own oracles and decode on
+"""The balancer's plain reference agrees with the program's own oracles on
 the CPU (the reference imports nothing of the program; these tests do)."""
 
 import jax
@@ -6,7 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench import check, deploy, reference
+from bench import check, reference
 from bench.tests import tiny
 
 
@@ -123,28 +123,6 @@ def test_completion_matches_the_program_oracle(seed):
                  ("ep_inflight_ewma", want.inflight_ewma),
                  ("ep_tput_ewma", want.tput_ewma)):
         np.testing.assert_array_equal(got[k], w)
-
-
-def test_forward_matches_the_program_decode():
-    """The full-sequence reference against the program's decode step fed
-    token by token through its cache (float32 on the CPU)."""
-    from repro.configs import get_config
-    from repro.models import model as M
-    cfg = get_config(tiny.APP["name"])
-    params = deploy.make_params(deploy.param_shapes(cfg, jnp.float32), 3)
-    toks = np.random.default_rng(0).integers(2, 512, (3, 6)).astype(np.int32)
-    cache = M.init_cache(cfg, 3, 8, jnp.float32)
-    steps = []
-    for p in range(6):
-        lg, cache = M.decode_step(cfg, params, jnp.asarray(toks[:, p:p + 1]),
-                                  jnp.full((3,), p, jnp.int32), cache)
-        steps.append(np.asarray(lg))
-    want = np.stack(steps, 1)
-    got = np.asarray(reference.forward_logits(tiny.APP, params,
-                                              jnp.asarray(toks)))
-    np.testing.assert_allclose(got, want, atol=2e-4, rtol=1e-4)
-    gaps = reference.served_gaps(got[:, :-1], want[:, :-1].argmax(-1))
-    assert gaps.max() < 1e-4
 
 
 def test_ewma_rounds_as_the_backend_does(monkeypatch):

@@ -7,7 +7,8 @@ from pathlib import Path
 import numpy as np
 import pytest
 
-from bench import peaks, run, trace
+from bench import costs, peaks, phases, registry, run, trace
+from bench.apps import dense
 from bench.metrics import (admit_commit_roofline_pct, admit_commit_us,
                            complete_us, device_idle_pct, step_mfu_pct,
                            tick_ms)
@@ -17,9 +18,11 @@ DATA = Path(__file__).resolve().parent / "data"
 
 def _events():
     """Two ticks (0-100 ns, 150-250 ns) with a submit span between them;
-    device ops overlapping inside the first, one op in the second."""
+    device ops overlapping inside the first, one op in the second; the
+    kernels whose costs were counted, as ``extract`` keeps them."""
     return {
         "device": "/device:TPU:0",
+        "kernels": ["xlb_admit_commit", "xlb_complete"],
         "ops": [["xlb_admit_commit", "xlb_admit_commit", 10, 10],
                 ["fusion.1", None, 32, 8],
                 ["custom-call.3", "xlb_complete", 30, 5],
@@ -49,6 +52,62 @@ def test_busy_union_kernels_and_gaps():
     assert gaps["tick"] == pytest.approx(145e-9)
     assert gaps["submit"] == pytest.approx(50e-9)
     assert sum(gaps.values()) == pytest.approx(s.window_s - s.busy_s)
+
+
+def test_extract_keeps_the_programs_spans_with_their_counters(tmp_path):
+    """A profile taken here (a CPU has no device plane): the harness's
+    spans go to ``host``, every ``loop.*`` span to ``phases`` with its
+    numeric metadata, whatever its name."""
+    import jax
+    import jax.numpy as jnp
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    with jax.profiler.TraceAnnotation("tick"):
+        with jax.profiler.TraceAnnotation("loop.admission") as span:
+            span.set_metadata(rows=3, batch=8)
+        with jax.profiler.TraceAnnotation("loop.relay") as span:
+            span.set_metadata(experts=2, note="text")
+            jnp.ones(8).sum().block_until_ready()
+    jax.profiler.stop_trace()
+    ev = trace.extract(trace.newest_xplane(tmp_path))
+    assert [h[0] for h in ev["host"]] == ["tick"]
+    assert [(p[0], p[3]) for p in ev["phases"]] == [
+        ("loop.admission", {"rows": 3, "batch": 8}),
+        ("loop.relay", {"experts": 2})]
+    tick = ev["host"][0]
+    for _, s, d, _ in ev["phases"]:
+        assert tick[1] <= s and s + d <= tick[1] + tick[2]
+    assert len(ev["scopes"]) == len(ev["ops"])
+
+
+def test_a_kernel_is_its_ops_exact_name():
+    """An op launches a kernel where its instruction is named the kernel,
+    with or without a ``.<n>`` suffix: a kernel whose name begins another's
+    takes none of the other's ops."""
+    kernels = ("xlb_admit", "xlb_admit_commit", "xlb_complete")
+    assert trace._kernel_of("xlb_admit_commit.2", kernels) \
+        == "xlb_admit_commit"
+    assert trace._kernel_of("xlb_admit.7", kernels) == "xlb_admit"
+    assert trace._kernel_of("xlb_admit", kernels) == "xlb_admit"
+    assert trace._kernel_of("xlb_complete.1", kernels[:2]) is None
+    for op in ("fusion.3", "xlb_admit_commit.2.clone", "xlb_admit_x.1",
+               "copy.xlb_complete"):
+        assert trace._kernel_of(op, kernels) is None
+
+
+@pytest.mark.parametrize("recorded", ["trace-fleet50.rpc-closed.json",
+                                      "phases-fleet50.rpc-closed.json"])
+def test_recorded_ops_keep_their_kernels(recorded):
+    """The chip's recorded ops, named again by the kernels that fleet50's
+    costs count, get the kernel they were recorded with, and a kernel
+    named after the admission scope takes none of them."""
+    ops = json.loads((DATA / recorded).read_text())["events"]["ops"]
+    kernels = ("xlb_admit", "xlb_admit_commit", "xlb_complete")
+    assert [trace._kernel_of(o[0], kernels) for o in ops] == \
+        [o[1] for o in ops]
+    assert sum(o[1] is not None for o in ops) == 24
 
 
 def test_no_device_or_no_tick_reads_nothing():
@@ -107,7 +166,7 @@ def test_recorded_chip_trace():
         lo, hi = min(t[0] for t in ticks), max(t[1] for t in ticks)
         # the closed loop launches each kernel and serve_step once a tick
         assert s.n_steps == s.n_ticks == len(ticks)
-        for k in trace.KERNELS:
+        for k in ("xlb_admit_commit", "xlb_complete"):
             assert s.kernels[k]["launches"] == s.n_ticks
         assert s.busy_s == pytest.approx(
             _raster_busy(rec["events"], lo, hi), rel=0.02)
@@ -128,3 +187,41 @@ def test_unknown_device_is_an_error():
     with pytest.raises(peaks.UnknownDevice):
         peaks.peaks_for("cpu")
     assert peaks.peaks_for("TPU v5 lite")["hbm_bytes"] == 819e9
+
+
+# What each reader read at the parent commit, its costs and decode FLOPs
+# counted for fleet50's shapes, on the two recorded traces of the chip
+# (the second with the program's spans and scopes).
+BEFORE = {
+    "trace-fleet50.rpc-closed.json": {
+        "tick_ms": 8.055495833333334,
+        "device_idle_pct": 96.34303599147374,
+        "step_mfu_pct": 0.018585410450736854,
+        "admit_commit_us": 32.7475,
+        "admit_commit_roofline_pct": 0.9618122756530267,
+        "complete_us": 2.4803333333333337,
+        "complete_roofline_pct": 1.6564003124949234},
+    "phases-fleet50.rpc-closed.json": {
+        "tick_ms": 6.8545755,
+        "device_idle_pct": 95.90768239404468,
+        "step_mfu_pct": 0.021841570852447464,
+        "admit_commit_us": 32.69291666666667,
+        "admit_commit_roofline_pct": 0.9634180950597604,
+        "complete_us": 0.6856666666666666,
+        "complete_roofline_pct": 5.991869093473372},
+}
+
+
+@pytest.mark.parametrize("name", sorted(BEFORE[min(BEFORE)]))
+@pytest.mark.parametrize("recorded", sorted(BEFORE))
+def test_existing_readers_read_as_before(fleet_state, recorded, name):
+    """Each reader that predates the program's spans reads, from the same
+    events, what it read before the harness took the spans in."""
+    ev = json.loads((DATA / recorded).read_text())["events"]
+    app = json.loads((run.ROOT / "bench" / "configs" / "fleet50.json")
+                     .read_text())["application"]
+    lr = run.LayerRun(trace.reduce(ev), peaks.peaks_for("TPU v5 lite"),
+                      costs.per_launch(fleet_state, {}, app, 256),
+                      dense.decode_flops(app, 400, 16), phases.reduce(ev))
+    got = registry.load("metrics", name).read(lr)
+    assert got == pytest.approx(BEFORE[recorded][name], rel=1e-12)
